@@ -45,8 +45,8 @@ def run(quick: bool = False):
     npool, pt, npages = 64, 16, 16
     g = hq // hkv
     q1 = jax.random.normal(ks[3], (b, hkv, g, dh), jnp.float32)
-    kp = jax.random.normal(ks[4], (npool, pt, hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[5], (npool, pt, hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[4], (npool, hkv, pt, dh), jnp.float32)
+    vp = jax.random.normal(ks[5], (npool, hkv, pt, dh), jnp.float32)
     tbl = jax.random.randint(ks[6], (b, npages), 0, npool)
     ln = jnp.array([npages * pt - 3], jnp.int32)
     us = _bench(ops.paged_attention, q1, kp, vp, tbl, ln)

@@ -15,6 +15,7 @@ import argparse
 import inspect
 import os
 import sys
+import traceback
 
 if __package__ in (None, ""):       # direct `python benchmarks/run.py`
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -140,16 +141,20 @@ def main(argv=None) -> None:
             print(f"wrote {args.json}", file=sys.stderr)
         return
     header()
+    failed = []
     for name, fn in full.items():
         if only and name not in only:
             continue
+        kw = ({"quick": args.quick}
+              if "quick" in inspect.signature(fn).parameters else {})
         try:
-            try:
-                fn(quick=args.quick)
-            except TypeError:
-                fn()
-        except Exception as e:  # noqa: BLE001
+            fn(**kw)
+        except Exception as e:  # noqa: BLE001 — report, run the rest
+            traceback.print_exc()
             print(f"{name},0.0,ERROR:{e!r}", file=sys.stderr)
+            failed.append(name)
+    if failed:
+        raise SystemExit(f"benchmarks raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -103,7 +103,9 @@ def n_attn_layers(cfg: ModelConfig) -> int:
 
 
 def _to_bytes(a) -> np.ndarray:
-    return np.asarray(a).reshape(a.shape[0], -1).view(np.uint8)
+    # a TPU array can come to the host in a strided layout, and a dtype
+    # view needs C order
+    return np.ascontiguousarray(a).reshape(a.shape[0], -1).view(np.uint8)
 
 
 def serialize_kv_layer(cfg: ModelConfig, state, slot: int, t0: int,
@@ -176,16 +178,16 @@ def deserialize_kv(cfg: ModelConfig, state, slot: int, t0: int,
 
 def layer_stream(cfg: ModelConfig, blocks: List[np.ndarray],
                  tm: Optional[TrafficManager] = None,
-                 tclass: TrafficClass = TrafficClass.KV_TRANSFER,
-                 interpret: bool = True
+                 tclass: TrafficClass = TrafficClass.KV_TRANSFER
                  ) -> Iterator[Tuple[int, np.ndarray]]:
     """Double-buffered per-layer LayerBlock stream from FullBlock pages.
 
     ``blocks``: the request's hit FullBlocks, each (L, page_tokens,
     row_bytes) uint8.  Yields ``(layer, rows)`` with ``rows`` of shape
     (n_blocks·page_tokens, row_bytes), gathered through the
-    kernels/kv_gather.py Pallas kernel (interpret mode on CPU) so the
-    HBM-placement path is the same pipelined-DMA gather the TPU runs.
+    kernels/kv_gather.py Pallas kernel (``kernels.ops``: compiled on
+    TPU, interpret mode on CPU) so the HBM-placement path is the same
+    pipelined-DMA gather the TPU runs.
 
     Pipeline shape: layer ``i+1``'s gather is *submitted* to the
     TrafficManager before layer ``i`` is yielded, so while the consumer
@@ -195,7 +197,7 @@ def layer_stream(cfg: ModelConfig, blocks: List[np.ndarray],
     The TrafficManager charges each gather's bytes to the KV traffic
     class, exercising the §5 ordering/doorbell-batching machinery.
     """
-    from repro.kernels.kv_gather import kv_layer_gather
+    from repro.kernels.ops import kv_layer_gather
 
     n_l = n_attn_layers(cfg)
     if not blocks or n_l == 0:
@@ -210,7 +212,7 @@ def layer_stream(cfg: ModelConfig, blocks: List[np.ndarray],
     buf: Dict[int, np.ndarray] = {}
 
     def fetch(layer: int):
-        out = kv_layer_gather(pool, table, layer=layer, interpret=interpret)
+        out = kv_layer_gather(pool, table, layer=layer)
         buf[layer] = np.asarray(out).reshape(n * pt, row)
 
     tm.submit(lambda: fetch(0), layer_bytes, tclass)

@@ -40,6 +40,11 @@ from repro.models.model import append_step
 
 PAGED_FAMILIES = ("dense", "vlm", "moe")
 
+# Compiled once per shape with the config static.  Called eagerly, each
+# step re-traces its layer scan into a new program and compiles it again.
+_append_step = jax.jit(append_step, static_argnums=1)
+_decode_step = jax.jit(decode_step, static_argnums=1)
+
 
 def uses_state_blob(cfg: ModelConfig) -> bool:
     return cfg.family in ("ssm", "hybrid")
@@ -157,8 +162,8 @@ class PrefillEngine:
                                     bi.cached - er.req.cached_tokens + bi.bsz]
             t = jnp.asarray([toks], jnp.int32)
             lengths = jnp.asarray([er.length], jnp.int32)
-            logits, er.state = append_step(self.params, self.cfg, t,
-                                           er.state, lengths)
+            logits, er.state = _append_step(self.params, self.cfg, t,
+                                            er.state, lengths)
             er.length += bi.bsz
             self.prefill_tokens += bi.bsz
             if er.length == er.prompt_len:
@@ -223,8 +228,8 @@ class DecodeEngine:
             return []
         toks = jnp.asarray(self.next_token, jnp.int32)
         lengths = jnp.asarray(self.lengths, jnp.int32)
-        logits, self.state = decode_step(self.params, self.cfg, toks,
-                                         self.state, lengths)
+        logits, self.state = _decode_step(self.params, self.cfg, toks,
+                                          self.state, lengths)
         self.decode_steps += 1
         nxt = np.asarray(jnp.argmax(logits, axis=-1))
         finished = []
@@ -254,7 +259,12 @@ class DecodeEngine:
         blocking runtime's behaviour)."""
         full_tokens = er.context_tokens + er.append_tokens + er.generated
         bt = self.layout.block_tokens
-        n_blocks = len(full_tokens) // bt
+        # the last generated token was never fed back through decode, so
+        # the state holds KV for lengths[slot] = len(full_tokens) - 1
+        # tokens (all of them when gen == 1): persist only whole blocks
+        # of those, or a block ending on that token would store a blank
+        # KV row that later rounds reuse as a hit
+        n_blocks = int(self.lengths[slot]) // bt
         start_block = er.req.cached_tokens // bt
         if uses_state_blob(self.cfg):
             blob = pickle.dumps(jax.tree.map(

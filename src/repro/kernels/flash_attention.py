@@ -35,10 +35,7 @@ NEG_INF = -1e30
 
 
 def tpu_params(*semantics):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:  # older jax spelling
-        return pltpu.TPUCompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in Pallas **interpret mode**
-— the kernel body runs in Python with the exact same blocking/masking
-logic the TPU lowering uses.  On TPU they compile through Mosaic.  The
-choice is automatic from the default backend, overridable per call.
+On CPU the kernels execute in Pallas **interpret mode** — the kernel
+body runs in Python with the exact same blocking/masking logic the TPU
+lowering uses.  On TPU they compile through Mosaic.  The choice is
+automatic from the default backend, overridable per call; any other
+backend is an error rather than a silent fall back to the interpreter.
 """
 from __future__ import annotations
 
@@ -17,7 +18,12 @@ from repro.kernels.paged_attention import paged_attention as _paged
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas lowering for backend {backend!r}")
 
 
 def flash_attention(q, k, v, *, causal=True, softcap=0.0, window=0,
@@ -39,13 +45,13 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
                   else interpret)
 
 
-def kv_layer_gather(pool, table, *, layer: int, interpret=None):
+def kv_layer_gather(pool, table, *, layer, interpret=None):
     return _gather(pool, table, layer=layer,
                    interpret=_interpret_default() if interpret is None
                    else interpret)
 
 
-def kv_layer_scatter(pool, table, stream, *, layer: int, interpret=None):
+def kv_layer_scatter(pool, table, stream, *, layer, interpret=None):
     return _scatter(pool, table, stream, layer=layer,
                     interpret=_interpret_default() if interpret is None
                     else interpret)

@@ -5,7 +5,7 @@ granularity the storage layer uses), addressed by a per-sequence block
 table.  One new token per sequence attends over its pages:
 
     q:           (batch, kv_heads, group, head_dim)
-    k/v_pool:    (n_pages, page_tokens, kv_heads, head_dim)
+    k/v_pool:    (n_pages, kv_heads, page_tokens, head_dim)
     block_table: (batch, max_pages) int32     — page ids per sequence
     lengths:     (batch,) int32               — valid tokens per sequence
 
@@ -14,7 +14,10 @@ innermost carrying online-softmax state; the block table and lengths
 ride in scalar-prefetch so each page's BlockSpec index_map can pick the
 right pool row (``table[b, i]``) while the DMA for page i+1 overlaps the
 compute on page i — the HBM→VMEM streaming analogue of the paper's
-layerwise loading.
+layerwise loading.  Heads sit before tokens in the pool so that one
+(head, page) block is a whole ``(page_tokens, head_dim)`` tile: the TPU
+lowering accepts a block's last two dims only when each is a multiple
+of (8, 128) or spans its array dim.
 """
 from __future__ import annotations
 
@@ -42,8 +45,8 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]                       # (g, dh)
-    k = k_ref[0, :, 0]                    # (page_tokens, dh)
-    v = v_ref[0, :, 0]
+    k = k_ref[0, 0]                       # (page_tokens, dh)
+    v = v_ref[0, 0]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale      # (g, pt)
@@ -75,10 +78,10 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    static_argnames=("softcap", "interpret"))
 def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
                     softcap: float = 0.0, interpret: bool = False):
-    """q (b, hkv, g, dh); pools (n_pages, pt, hkv, dh);
+    """q (b, hkv, g, dh); pools (n_pages, hkv, pt, dh);
     block_table (b, max_pages) i32; lengths (b,) i32 -> (b, hkv, g, dh)."""
     b, hkv, g, dh = q.shape
-    n_pool, pt, _, _ = k_pool.shape
+    n_pool, _, pt, _ = k_pool.shape
     max_pages = block_table.shape[1]
     scale = 1.0 / math.sqrt(dh)
 
@@ -92,10 +95,10 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, dh),
                          lambda b_, h, pi, tbl, ln: (b_, h, 0, 0)),
-            pl.BlockSpec((1, pt, 1, dh),
-                         lambda b_, h, pi, tbl, ln: (tbl[b_, pi], 0, h, 0)),
-            pl.BlockSpec((1, pt, 1, dh),
-                         lambda b_, h, pi, tbl, ln: (tbl[b_, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, pt, dh),
+                         lambda b_, h, pi, tbl, ln: (tbl[b_, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, pt, dh),
+                         lambda b_, h, pi, tbl, ln: (tbl[b_, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dh),
                                lambda b_, h, pi, tbl, ln: (b_, h, 0, 0)),

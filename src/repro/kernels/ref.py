@@ -32,21 +32,23 @@ def flash_attention_ref(q, k, v, *, causal=True, softcap=0.0, window=0):
 
 def paged_attention_ref(q, k_pool, v_pool, block_table, lengths, *,
                         softcap=0.0):
-    """q (b,hkv,g,dh); pools (n,pt,hkv,dh); table (b,np); lengths (b,)."""
+    """q (b,hkv,g,dh); pools (n,hkv,pt,dh); table (b,np); lengths (b,)."""
     b, hkv, g, dh = q.shape
-    n_pool, pt, _, _ = k_pool.shape
+    n_pool, _, pt, _ = k_pool.shape
     np_ = block_table.shape[1]
-    # materialise per-sequence KV: (b, np*pt, hkv, dh)
-    k = k_pool[block_table].reshape(b, np_ * pt, hkv, dh)
-    v = v_pool[block_table].reshape(b, np_ * pt, hkv, dh)
-    s = jnp.einsum("bngd,bknd->bngk", q.astype(jnp.float32),
+    # materialise per-sequence KV: (b, hkv, np*pt, dh)
+    k = k_pool[block_table].transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, np_ * pt, dh)
+    v = v_pool[block_table].transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, np_ * pt, dh)
+    s = jnp.einsum("bngd,bnkd->bngk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(dh)
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     valid = jnp.arange(np_ * pt)[None, :] < lengths[:, None]
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bngk,bknd->bngd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bngk,bnkd->bngd", p, v.astype(jnp.float32))
     return o.astype(q.dtype)
 
 

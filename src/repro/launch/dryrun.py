@@ -1,4 +1,7 @@
 import os
+# 512 fake CPU devices; pinned to the CPU so this never takes an
+# attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

@@ -11,6 +11,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +23,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         f"need {need} devices, have {len(devs)} — the dry-run entrypoint "
         "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
         "before any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    # the model places activations with with_sharding_constraint, which
+    # needs Auto axes; jax.make_mesh defaults to Explicit ones
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

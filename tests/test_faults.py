@@ -343,7 +343,7 @@ def test_serving_chaos_straggle_hedged(cfg_params, baseline):
 def test_serving_chaos_de_death_recovers(cfg_params, baseline):
     """A DE dies mid-run: its in-flight rounds restart on the survivor
     from persisted KV, exactly-once persists, identical tokens."""
-    fs = FaultSchedule(deaths=[EngineDeath(0.65, (2, 0))])
+    fs = FaultSchedule(deaths=[EngineDeath(0.6, (2, 0))])
     sys_, sessions = _serve(cfg_params, faults=fs)
     st_ = _assert_chaos_invariants(sys_, sessions, baseline)
     assert st_["engine_deaths"] == 1

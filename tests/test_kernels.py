@@ -72,8 +72,8 @@ def test_flash_attention_noncausal():
 def test_paged_attention_sweep(dtype, b, hkv, g, dh, npool, pt, npages):
     ks = jax.random.split(KEY, 5)
     q = rand(ks[0], (b, hkv, g, dh), dtype)
-    kp = rand(ks[1], (npool, pt, hkv, dh), dtype)
-    vp = rand(ks[2], (npool, pt, hkv, dh), dtype)
+    kp = rand(ks[1], (npool, hkv, pt, dh), dtype)
+    vp = rand(ks[2], (npool, hkv, pt, dh), dtype)
     tbl = jax.random.randint(ks[3], (b, npages), 0, npool)
     lengths = jax.random.randint(ks[4], (b,), 1, npages * pt)
     out = ops.paged_attention(q, kp, vp, tbl, lengths)

@@ -41,6 +41,18 @@ def test_serialize_roundtrip(arch):
     np.testing.assert_array_equal(kv, kv1)
 
 
+def test_serialize_strided_host_layout():
+    """A TPU array can reach the host with non-C strides; serialising it
+    must give the same bytes as the C-ordered array."""
+    cfg = get_config("qwen1.5-0.5b").reduced()
+    st = jax.tree.map(
+        lambda a: jax.random.normal(KEY, a.shape).astype(a.dtype),
+        init_decode_state(cfg, 2, 16))
+    strided = jax.tree.map(lambda a: np.asfortranarray(np.asarray(a)), st)
+    np.testing.assert_array_equal(kvio.serialize_kv(cfg, strided, 1, 3, 11),
+                                  kvio.serialize_kv(cfg, st, 1, 3, 11))
+
+
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "zamba2-2.7b", "ds27b"])
 def test_slot_get_set_roundtrip(arch):
     cfg = get_config(arch).reduced()

@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.config import TierConfig
+from repro.engines import kvio
 from repro.models import decode_step, forward, init_decode_state, init_params
 from repro.serving import ServingSystem
 from repro.sim.traces import Round, Trajectory
@@ -84,6 +85,27 @@ def test_generation_with_cache_reuse_matches_reference(mode):
         f"cache-reuse generation diverged from cache-free reference "
         f"({mode}); first mismatch at "
         f"{next(i for i, (a, b) in enumerate(zip(ctx, ref_context)) if a != b)}")
+
+
+def test_persisted_blocks_hold_the_forward_kv():
+    """Round 1's prompt + gen ends exactly on a block boundary.  The last
+    generated token is never fed back through decode, so its KV row does
+    not exist yet: that block must wait for round 2, and every block the
+    trie serves holds exactly the KV a cache-free forward computes."""
+    cfg = get_config("qwen1.5-0.5b").reduced()
+    params = init_params(cfg, KEY)
+    sys_ = ServingSystem(cfg, params, block_tokens=16, max_seq=160,
+                         de_slots=2, seed=0)
+    sessions = sys_.run_offline([Trajectory(0, [Round(12, 4),
+                                                Round(16, 4)])])
+    ctx = sessions[0].context
+    hit, refs = sys_.trie.match(ctx)
+    assert hit == 32
+    _, state = forward(params, cfg, jnp.asarray([ctx], jnp.int32),
+                       return_state=True)
+    want = kvio.serialize_kv(cfg, state, 0, 0, hit)
+    got = np.concatenate([sys_.store.peek(r) for r in refs], axis=1)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_multi_agent_multi_engine():

@@ -1,0 +1,87 @@
+"""Ahead-of-time compiles of the Pallas kernels for a v5e chip.
+
+Nothing here runs on a chip: each kernel is lowered and compiled by the
+TPU compiler for a *described* v5e device at qwen1.5-0.5b widths (16 kv
+heads, head_dim 64, 24 layers, bf16 KV, 16-token FullBlock pages), so a
+BlockSpec or VMEM budget the chip would refuse fails here even though
+the interpret-mode tests pass.
+
+The topology is described inside a module fixture, never while a module
+is imported: only one process may hold the TPU library, and every
+pytest-xdist worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.engines.kvio import kv_row_bytes, n_attn_layers
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.kv_gather import kv_layer_gather, kv_layer_scatter
+from repro.kernels.paged_attention import paged_attention
+
+PAGE_TOKENS = 16          # chip_smoke.py's FullBlock size
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def qwen():
+    return get_config("qwen1.5-0.5b")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", ["gather", "scatter"])
+def test_kv_layer_gather_scatter_compile(one_chip, qwen, op):
+    n_pool, n = 64, 48
+    pool = _sds((n_pool, n_attn_layers(qwen), PAGE_TOKENS,
+                 kv_row_bytes(qwen)), jnp.uint8, one_chip)
+    table = _sds((n,), jnp.int32, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    if op == "gather":
+        lowered = kv_layer_gather.lower(pool, table, layer=layer)
+    else:
+        stream = _sds((n, PAGE_TOKENS, kv_row_bytes(qwen)), jnp.uint8,
+                      one_chip)
+        lowered = kv_layer_scatter.lower(pool, table, stream, layer=layer)
+    _assert_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("s_app,s_kv", [(256, 1024), (40, 296)],
+                         ids=["aligned", "unaligned"])
+def test_flash_attention_compile(one_chip, qwen, s_app, s_kv):
+    hq, hkv, dh = qwen.n_heads, qwen.n_kv_heads, qwen.head_dim
+    q = _sds((1, hq, s_app, dh), jnp.bfloat16, one_chip)
+    k = _sds((1, hkv, s_kv, dh), jnp.bfloat16, one_chip)
+    _assert_kernel(flash_attention.lower(q, k, k).compile())
+
+
+def test_paged_attention_compile(one_chip, qwen):
+    b, max_pages, n_pool = 8, 1024 // PAGE_TOKENS, 512
+    hkv, dh = qwen.n_kv_heads, qwen.head_dim
+    q = _sds((b, hkv, qwen.n_heads // hkv, dh), jnp.bfloat16, one_chip)
+    pool = _sds((n_pool, hkv, PAGE_TOKENS, dh), jnp.bfloat16, one_chip)
+    table = _sds((b, max_pages), jnp.int32, one_chip)
+    lengths = _sds((b,), jnp.int32, one_chip)
+    _assert_kernel(paged_attention.lower(q, pool, pool, table,
+                                         lengths).compile())
