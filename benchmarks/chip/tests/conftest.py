@@ -1,0 +1,7 @@
+"""The benchmark's tests import its flat modules and the program."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(HERE, "..", "..", "..", "src"))
+sys.path.insert(0, os.path.join(HERE, ".."))
