@@ -160,11 +160,6 @@ class TrafficManager:
     tests.
     """
 
-    #: optional flight recorder (repro.obs.Tracer) + track label,
-    #: attached by the owning runtime; None = untraced
-    tracer = None
-    track = "traffic"
-
     def __init__(self, cost: SubmitCostModel = SubmitCostModel(),
                  doorbell_batch: int = 32, pace_threshold: float = 0.5):
         self.cost = cost
@@ -262,10 +257,6 @@ class TrafficManager:
         self._inflight.extend(posted)
         for t in deferred:       # sort_key intact: order is preserved
             heapq.heappush(self._q, t)
-        if self.tracer is not None:
-            self.tracer.event(self.track, "flush", posted=len(posted),
-                              deferred=len(deferred),
-                              posted_bytes=sum(t.nbytes for t in posted))
         return len(posted)
 
     # -- completion half ---------------------------------------------------
@@ -287,8 +278,6 @@ class TrafficManager:
                 cbs, t.cbs = t.cbs, None
                 for cb in cbs or ():
                     cb()
-        if n and self.tracer is not None:
-            self.tracer.event(self.track, "poll", completed=n)
         return n
 
     @property

@@ -178,7 +178,8 @@ def deserialize_kv(cfg: ModelConfig, state, slot: int, t0: int,
 
 def layer_stream(cfg: ModelConfig, blocks: List[np.ndarray],
                  tm: Optional[TrafficManager] = None,
-                 tclass: TrafficClass = TrafficClass.KV_TRANSFER
+                 tclass: TrafficClass = TrafficClass.KV_TRANSFER,
+                 tracer=None, track: str = "kvio"
                  ) -> Iterator[Tuple[int, np.ndarray]]:
     """Double-buffered per-layer LayerBlock stream from FullBlock pages.
 
@@ -196,13 +197,23 @@ def layer_stream(cfg: ModelConfig, blocks: List[np.ndarray],
     double-buffering the paper overlaps with per-layer prefill compute.
     The TrafficManager charges each gather's bytes to the KV traffic
     class, exercising the §5 ordering/doorbell-batching machinery.
+
+    With a ``tracer`` (repro.obs.Tracer) the stack and upload of the
+    pool is the host region ``pe.install.upload`` and each layer's
+    gather, rows brought to the host included, ``pe.install.gather``,
+    both on ``track``.
     """
     from repro.kernels.ops import kv_layer_gather
 
     n_l = n_attn_layers(cfg)
     if not blocks or n_l == 0:
         return
-    pool = jnp.asarray(np.stack(blocks))      # (n_blocks, L, pt, row)
+    if tracer is None:
+        pool = jnp.asarray(np.stack(blocks))  # (n_blocks, L, pt, row)
+    else:
+        with tracer.region(track, "pe.install.upload",
+                           bytes=sum(b.nbytes for b in blocks)):
+            pool = jnp.asarray(np.stack(blocks))
     n, _, pt, row = pool.shape
     table = jnp.arange(n, dtype=jnp.int32)
     layer_bytes = int(n * pt * row)
@@ -211,9 +222,17 @@ def layer_stream(cfg: ModelConfig, blocks: List[np.ndarray],
         tm = TrafficManager()
     buf: Dict[int, np.ndarray] = {}
 
-    def fetch(layer: int):
+    def gather(layer: int) -> np.ndarray:
         out = kv_layer_gather(pool, table, layer=layer)
-        buf[layer] = np.asarray(out).reshape(n * pt, row)
+        return np.asarray(out).reshape(n * pt, row)
+
+    def fetch(layer: int):
+        if tracer is None:
+            buf[layer] = gather(layer)
+            return
+        with tracer.region(track, "pe.install.gather", layer=layer,
+                           bytes=layer_bytes):
+            buf[layer] = gather(layer)
 
     tm.submit(lambda: fetch(0), layer_bytes, tclass)
     for li in range(n_l):

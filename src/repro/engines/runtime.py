@@ -69,12 +69,17 @@ class EngineRequest:
 
 
 class PrefillEngine:
+    #: optional flight recorder (repro.obs.Tracer) for host regions,
+    #: attached by the owning runtime; None = untraced
+    tracer = None
+
     def __init__(self, eid, cfg: ModelConfig, params, store: MemoryKVStore,
                  layout: BlockLayout, max_seq: int,
                  quota_s: float = 0.300, layerwise: bool = True,
                  chunk_tokens: Optional[int] = None,
                  class_aware: bool = False):
         self.eid = eid
+        self.track = f"engine/pe{eid}"
         self.cfg = cfg
         self.params = params
         self.store = store
@@ -105,7 +110,20 @@ class PrefillEngine:
         the next layer's gather is already in flight on this engine's
         TrafficManager (double buffering).  The non-layerwise path is
         the whole-prompt bulk install, kept for the Fig. 12 ablation.
+        With a tracer: the host region ``pe.install``, and inside it
+        ``pe.install.upload``/``.gather`` (kvio.layer_stream) and one
+        ``pe.install.place`` per layer.
         """
+        tr = self.tracer
+        if tr is None:
+            self._install(er, payload)
+            return
+        with tr.region(self.track, "pe.install", rid=er.req.rid,
+                       hit_tokens=er.req.cached_tokens):
+            self._install(er, payload)
+
+    def _install(self, er: EngineRequest, payload):
+        tr = self.tracer
         er.state = init_decode_state(self.cfg, 1, self.max_seq)
         hit = er.req.cached_tokens
         if uses_state_blob(self.cfg):
@@ -115,9 +133,17 @@ class PrefillEngine:
         elif payload:
             if self.layerwise:
                 for li, rows in kvio.layer_stream(self.cfg, payload,
-                                                  tm=self.tm):
-                    er.state = kvio.deserialize_kv_layer(
-                        self.cfg, er.state, 0, 0, li, rows[:hit])
+                                                  tm=self.tm, tracer=tr,
+                                                  track=self.track):
+                    rows = rows[:hit]
+                    if tr is None:
+                        er.state = kvio.deserialize_kv_layer(
+                            self.cfg, er.state, 0, 0, li, rows)
+                    else:
+                        with tr.region(self.track, "pe.install.place",
+                                       layer=li, bytes=rows.nbytes):
+                            er.state = kvio.deserialize_kv_layer(
+                                self.cfg, er.state, 0, 0, li, rows)
             else:
                 kv_bytes = np.concatenate(payload, axis=1)   # (L, hit, row)
                 er.state = kvio.deserialize_kv(self.cfg, er.state, 0, 0,
@@ -136,7 +162,8 @@ class PrefillEngine:
     # -- compute ---------------------------------------------------------
     def step(self) -> List[EngineRequest]:
         """Run one quota-packed forward batch; returns requests whose
-        prefill completed this step."""
+        prefill completed this step.  With a tracer the batch runs in
+        the host region ``pe.prefill``."""
         self.last_step_items = []
         self.last_step_chunked = []
         if not self.fifo:
@@ -155,6 +182,14 @@ class PrefillEngine:
                 works.pop(0)
         self.fifo = [(w, byrid[w.rid]) for w in works]
         self.last_step_items = [(bi.cached, bi.bsz) for bi in batch]
+        if self.tracer is None:
+            return self._prefill(batch, byrid)
+        with self.tracer.region(self.track, "pe.prefill",
+                                tokens=sum(bi.bsz for bi in batch),
+                                items=len(batch)):
+            return self._prefill(batch, byrid)
+
+    def _prefill(self, batch: List[BatchItem], byrid) -> List[EngineRequest]:
         done = []
         for bi in batch:
             er = byrid[bi.rid]
@@ -175,10 +210,15 @@ class PrefillEngine:
 
 
 class DecodeEngine:
+    #: optional flight recorder (repro.obs.Tracer) for host regions,
+    #: attached by the owning runtime; None = untraced
+    tracer = None
+
     def __init__(self, eid, cfg: ModelConfig, params, store: MemoryKVStore,
                  trie: BlockTrie, layout: BlockLayout, max_seq: int,
                  n_slots: int = 8, blob_store: StateBlobStore | None = None):
         self.eid = eid
+        self.track = f"engine/de{eid}"
         self.cfg = cfg
         self.params = params
         self.store = store
@@ -220,12 +260,21 @@ class DecodeEngine:
         return slot
 
     def step(self) -> List[EngineRequest]:
-        """One decode step over all active slots; returns finished."""
+        """One decode step over all active slots; returns finished.
+        With a tracer a step that runs is the host region ``de.decode``
+        (the persists of the rounds it finishes nest inside it)."""
         self.last_step_ctxs = [int(self.lengths[s])
                                for s, er in enumerate(self.slots)
                                if er is not None]
         if all(s is None for s in self.slots):
             return []
+        if self.tracer is None:
+            return self._decode()
+        with self.tracer.region(self.track, "de.decode",
+                                slots=len(self.last_step_ctxs)):
+            return self._decode()
+
+    def _decode(self) -> List[EngineRequest]:
         toks = jnp.asarray(self.next_token, jnp.int32)
         lengths = jnp.asarray(self.lengths, jnp.int32)
         logits, self.state = _decode_step(self.params, self.cfg, toks,
@@ -256,7 +305,19 @@ class DecodeEngine:
         write execution and the trie insert are the *completion* half:
         with ``defer_persist`` they wait parked in ``pending_persist``
         for the system's flush; otherwise they drain inline (the
-        blocking runtime's behaviour)."""
+        blocking runtime's behaviour).  With a tracer: the host region
+        ``de.persist``, and inside it ``de.persist.copy``, the state's
+        rows brought to the host and cut into contiguous blocks."""
+        tr = self.tracer
+        if tr is None:
+            self._persist_slot(slot, er)
+            return
+        with tr.region(self.track, "de.persist", rid=er.req.rid) as r:
+            r.args["blocks"], r.args["bytes"] = self._persist_slot(slot, er)
+
+    def _persist_slot(self, slot: int, er: EngineRequest) -> Tuple[int, int]:
+        """:meth:`_persist`'s work; returns the FullBlocks and bytes it
+        submitted."""
         full_tokens = er.context_tokens + er.append_tokens + er.generated
         bt = self.layout.block_tokens
         # the last generated token was never fed back through decode, so
@@ -277,17 +338,20 @@ class DecodeEngine:
                 self.pending_persist.append((er, None))
             else:
                 self.tm.drain()
-            return
+            return 0, len(blob)
         if n_blocks <= start_block:
             if self.defer_persist:
                 self.pending_persist.append((er, None))
-            return
-        kv_bytes = kvio.serialize_kv(self.cfg, self.state, slot,
-                                     start_block * bt, n_blocks * bt)
-        new_refs = [self.store.alloc_ref()
-                    for _ in range(n_blocks - start_block)]
-        for i, ref in enumerate(new_refs):
-            blk = np.ascontiguousarray(kv_bytes[:, i * bt:(i + 1) * bt])
+            return 0, 0
+        tr = self.tracer
+        if tr is None:
+            blocks = self._copy_blocks(slot, start_block, n_blocks)
+        else:
+            with tr.region(self.track, "de.persist.copy") as r:
+                blocks = self._copy_blocks(slot, start_block, n_blocks)
+                r.args["bytes"] = sum(b.nbytes for b in blocks)
+        new_refs = [self.store.alloc_ref() for _ in blocks]
+        for ref, blk in zip(new_refs, blocks):
             self.tm.submit(lambda r=ref, b=blk: self.store.write_block(r, b),
                            blk.nbytes, TrafficClass.KV_TRANSFER)
         finalize = lambda toks=full_tokens[:n_blocks * bt], refs=new_refs: \
@@ -297,3 +361,14 @@ class DecodeEngine:
         else:
             self.tm.drain()
             finalize()
+        return len(blocks), sum(b.nbytes for b in blocks)
+
+    def _copy_blocks(self, slot: int, start_block: int,
+                     n_blocks: int) -> List[np.ndarray]:
+        """The slot's KV rows of blocks [start_block, n_blocks) on the
+        host, one contiguous FullBlock each."""
+        bt = self.layout.block_tokens
+        kv_bytes = kvio.serialize_kv(self.cfg, self.state, slot,
+                                     start_block * bt, n_blocks * bt)
+        return [np.ascontiguousarray(kv_bytes[:, i * bt:(i + 1) * bt])
+                for i in range(n_blocks - start_block)]

@@ -63,10 +63,22 @@ class KVStore:
 class MemoryKVStore(KVStore):
     """In-memory FullBlock store (engine runtime / tests)."""
 
+    #: optional flight recorder (repro.obs.Tracer) for host regions,
+    #: attached by the owning runtime; None = untraced
+    tracer = None
+
     def __init__(self, layout: BlockLayout):
         super().__init__(layout)
         self._data: Dict[int, np.ndarray] = {}
         self._lock = threading.Lock()
+
+    def write_block(self, ref: int, block) -> None:
+        """With a tracer, the host region ``store.write``."""
+        if self.tracer is None:
+            super().write_block(ref, block)
+            return
+        with self.tracer.region("store", "store.write", bytes=block.nbytes):
+            super().write_block(ref, block)
 
     def _put(self, ref: int, block: np.ndarray):
         assert block.shape == self.layout.full_block_shape(), (

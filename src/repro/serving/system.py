@@ -280,9 +280,11 @@ class ServingSystem:
             for node_id, tier in self.tiers.items():
                 tier.tracer = tracer
                 tier.track = f"tier/node{node_id}"
+            # host regions (Tracer.region): the tick's phases here, the
+            # engines' steps, installs and persists, the store's writes
             for eng in (*self.pes.values(), *self.des.values()):
-                eng.tm.tracer = tracer
-                eng.tm.track = f"traffic/node{eng.eid[0]}"
+                eng.tracer = tracer
+            self.store.tracer = tracer
 
     # ------------------------------------------------------------------
     def _all_tms(self) -> Iterator[TrafficManager]:
@@ -409,6 +411,15 @@ class ServingSystem:
                 pass
 
     def _schedule_tick(self) -> int:
+        """Tick phase 1; with a tracer, the host region
+        ``serve.schedule`` (the store reads of the issued reads in it)."""
+        if self.tracer is None:
+            return self._schedule()
+        with self.tracer.region("serve", "serve.schedule") as r:
+            n = r.args["issued"] = self._schedule()
+        return n
+
+    def _schedule(self) -> int:
         self._fetch_groups()
         # decide paths for every ready request first (read queues build up
         # across the batch of decisions, as on a live cluster), then read
@@ -902,7 +913,15 @@ class ServingSystem:
     def _poll_all(self) -> int:
         """Complete every in-flight transfer (tick phase 4): completion
         callbacks mark requests install-ready / PD-ready and run persist
-        finalisation + next-round submission."""
+        finalisation + next-round submission.  With a tracer, the host
+        region ``serve.poll`` (deferred store writes run in it)."""
+        if self.tracer is None:
+            return self._poll()
+        with self.tracer.region("serve", "serve.poll") as r:
+            n = r.args["completed"] = self._poll()
+        return n
+
+    def _poll(self) -> int:
         n = 0
         progress = True
         while progress:
@@ -1151,8 +1170,7 @@ class ServingSystem:
                                  tier_handoff_bytes=handoff)
         if self.tracer is not None:
             eng = self.pes.get(eid) or self.des[eid]
-            eng.tm.tracer = self.tracer
-            eng.tm.track = f"traffic/node{eid[0]}"
+            eng.tracer = self.tracer
             self.tracer.span(
                 "reconfig", "drain", rec.t_begin, self.clock.now,
                 engine=list(eid),
@@ -1325,7 +1343,19 @@ class ServingSystem:
         and land at phase 4's poll, so the clock charges
         ``max(transfer, compute)``.  Blocking: the same phases with
         inline drains — the clock charges ``transfer + compute``.
+        With a tracer the tick is the host region ``serve.tick``.
         """
+        tr = self.tracer
+        if tr is None:
+            return self._tick_phases()
+        steps0 = sum(de.decode_steps for de in self.des.values())
+        with tr.region("serve", "serve.tick") as r:
+            act = self._tick_phases()
+            r.args.update(inflight=len(self._inflight), decode_steps=sum(
+                de.decode_steps for de in self.des.values()) - steps0)
+        return act
+
+    def _tick_phases(self) -> int:
         self._tick_io = TickIo()
         self._tick_compute = 0.0
         self._tick_coll = {}
@@ -1353,9 +1383,6 @@ class ServingSystem:
             dt = self._tick_io.serial_seconds() + self._tick_compute
         self.clock.advance(dt + self._submit_overhead_delta())
         self._flush_stamps()
-        if self.tracer is not None:
-            self.tracer.counter("system/load",
-                                inflight=len(self._inflight))
         return act
 
     # ------------------------------------------------------------------
@@ -1403,7 +1430,11 @@ class ServingSystem:
                     if nt is None:
                         raise RuntimeError(
                             "serving runtime stalled with no pending events")
-                    self.clock.jump_to(nt)
+                    if self.tracer is None:
+                        self.clock.jump_to(nt)
+                    else:
+                        with self.tracer.region("serve", "serve.wait"):
+                            self.clock.jump_to(nt)
             else:
                 raise RuntimeError("serving system did not converge")
         finally:

@@ -369,3 +369,151 @@ def test_serving_audit_detects_missing_read_event(serving_run):
                           check_persists=False)
     finally:
         tr.spans[:] = snap
+
+
+# ---------------------------------------------------------------------------
+# host regions (Tracer.region): host clock, device trace, compiles
+# ---------------------------------------------------------------------------
+
+#: every region the served path records, with the args each carries
+REGION_ARGS = {
+    "serve.tick": {"inflight", "decode_steps"},
+    "serve.schedule": {"issued"},
+    "serve.poll": {"completed"},
+    "serve.wait": set(),
+    "pe.prefill": {"tokens", "items"},
+    "pe.install": {"rid", "hit_tokens"},
+    "pe.install.upload": {"bytes"},
+    "pe.install.gather": {"layer", "bytes"},
+    "pe.install.place": {"layer", "bytes"},
+    "de.decode": {"slots"},
+    "de.persist": {"rid", "blocks", "bytes"},
+    "de.persist.copy": {"bytes"},
+    "store.write": {"bytes"},
+}
+
+
+def _host_plane_events(trace_dir):
+    """(name, duration_ns) of every event on the profiler's host
+    planes."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    data = ProfileData.from_file(str(path))
+    return [(ev.name, ev.duration_ns) for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_region_lands_on_the_device_trace_host_plane(tmp_path):
+    import time
+
+    import jax
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.region("serve", "test.region.clock") as r:
+            time.sleep(0.02)
+    durs = [d for n, d in _host_plane_events(tmp_path)
+            if n == "test.region.clock"]
+    assert len(durs) == 1
+    own = r.t1 - r.t0
+    assert abs(durs[0] - own) <= max(0.1 * own, 50_000)
+
+
+def test_nested_regions_record_their_parent():
+    tr, other = Tracer(), Tracer()
+    with tr.region("a", "outer") as outer:
+        with other.region("b", "elsewhere") as elsewhere:
+            with tr.region("a", "inner", k=1) as inner:
+                inner.args["n"] = 2
+        with tr.region("a", "sibling") as sibling:
+            pass
+    assert outer.parent is None and elsewhere.parent is None
+    assert inner.parent is outer and sibling.parent is outer
+    assert inner.args == {"k": 1, "n": 2}
+    assert [r.name for r in tr.regions] == ["outer", "inner", "sibling"]
+    assert all(r.t1 >= r.t0 >= 0 for r in tr.regions)
+    assert outer.t0 <= inner.t0 <= inner.t1 <= sibling.t0 <= outer.t1
+
+
+def test_regions_stay_out_of_the_modelled_export():
+    tr = Tracer(now_fn=lambda: 0.0)
+    tr.span("req/1", "scheduled", 0.0, 1.0)
+    before = tr.export_bytes()
+    with tr.region("serve", "serve.tick", inflight=1):
+        pass
+    assert tr.export_bytes() == before
+    host = tr.regions_chrome_trace()["traceEvents"]
+    assert [e["name"] for e in host if e["ph"] == "X"] == ["serve.tick"]
+    threads = {e["args"]["name"] for e in host
+               if e["name"] == "thread_name"}
+    assert threads == {"host/serve"}
+
+
+@pytest.fixture(scope="module")
+def region_run(tmp_path_factory):
+    """A traced serving run under the profiler: two-round sessions
+    (the second rounds hit the first's blocks), the last arriving after
+    an idle gap; a ``max_seq`` no other test uses, so its programs
+    compile inside the run."""
+    import jax
+    from repro.configs import get_config
+    from repro.models import init_params
+    from repro.serving import ServingSystem
+    from repro.sim.spec import REDUCED_TEST_NODE
+
+    cfg = get_config("qwen1.5-0.5b").reduced()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tr = Tracer()
+    s = ServingSystem(cfg, params, n_pe=1, n_de=1, block_tokens=16,
+                      max_seq=176, de_slots=2, seed=0, split_reads=True,
+                      node=REDUCED_TEST_NODE, tracer=tr)
+    trajs = [Trajectory(i, [Round(40, 4, 0.0), Round(24, 4, 0.0)])
+             for i in range(3)]
+    trace_dir = tmp_path_factory.mktemp("xplane")
+    with jax.profiler.trace(str(trace_dir)):
+        s.run_online(trajs, [0.0, 0.0, 50.0])
+    return {"system": s, "tracer": tr,
+            "host": _host_plane_events(trace_dir)}
+
+
+def test_served_path_records_every_region_with_its_args(region_run):
+    tr = region_run["tracer"]
+    seen = {}
+    for r in tr.regions:
+        seen.setdefault(r.name, set()).update(r.args)
+        assert r.t1 >= r.t0
+    assert seen == REGION_ARGS
+    hits = [r for r in tr.regions if r.name == "pe.install"
+            and r.args["hit_tokens"]]
+    assert hits
+    for r in tr.regions:
+        if r.name.startswith("pe.install."):
+            assert r.parent.name == "pe.install"
+            assert r.parent.args["hit_tokens"] > 0
+    copies = [r for r in tr.regions if r.name == "de.persist.copy"]
+    assert copies and all(r.parent.name == "de.persist" for r in copies)
+    assert sum(r.args["bytes"] for r in copies) == \
+        region_run["system"].store.bytes_written
+    steps = sum(r.args["decode_steps"] for r in tr.regions
+                if r.name == "serve.tick")
+    assert steps == sum(de.decode_steps
+                        for de in region_run["system"].des.values())
+    assert steps == sum(1 for r in tr.regions if r.name == "de.decode")
+
+
+def test_every_region_is_on_the_device_trace_host_plane(region_run):
+    from collections import Counter
+    on_plane = Counter(n for n, _ in region_run["host"])
+    for name, n in Counter(r.name for r in
+                           region_run["tracer"].regions).items():
+        assert on_plane[name] == n, name
+
+
+def test_compile_inside_a_region_names_it(region_run):
+    compiles = [(track, args) for track, name, _, args
+                in region_run["tracer"].host_events if name == "compile"]
+    assert ("engine/pe(0, 0)", "pe.prefill") in {
+        (track, a["region"]) for track, a in compiles}
+    assert all(a["duration_s"] > 0 for _, a in compiles)
