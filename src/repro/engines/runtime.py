@@ -19,6 +19,7 @@ SSM/hybrid archs carry an opaque state-blob instead of per-token KV
 from __future__ import annotations
 
 import pickle
+import sys
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -42,8 +43,28 @@ PAGED_FAMILIES = ("dense", "vlm", "moe")
 
 # Compiled once per shape with the config static.  Called eagerly, each
 # step re-traces its layer scan into a new program and compiles it again.
+# The decode step donates its state (argument 3): the dense cache is
+# updated in place, and the state passed in is deleted.
 _append_step = jax.jit(append_step, static_argnums=1)
-_decode_step = jax.jit(decode_step, static_argnums=1)
+_decode_step = jax.jit(decode_step, static_argnums=1, donate_argnums=3)
+
+
+def _held_elsewhere(state) -> bool:
+    """Whether anything but the caller's one name holds ``state`` or a
+    dict or array inside it, by CPython's reference counts.  A donated
+    step deletes the buffers it is given, under any such holder."""
+    # the caller's name, this frame's and getrefcount's argument
+    if sys.getrefcount(state) > 3:
+        return True
+    todo = list(state.values())
+    while todo:
+        node = todo.pop()
+        # its parent, this frame's name and getrefcount's argument
+        if sys.getrefcount(node) > 3:
+            return True
+        if isinstance(node, dict):
+            todo.extend(node.values())
+    return False
 
 
 def uses_state_blob(cfg: ModelConfig) -> bool:
@@ -277,8 +298,14 @@ class DecodeEngine:
     def _decode(self) -> List[EngineRequest]:
         toks = jnp.asarray(self.next_token, jnp.int32)
         lengths = jnp.asarray(self.lengths, jnp.int32)
+        # the step takes the state over (donated): its buffers are then
+        # deleted, so a state that is also held outside the engine is
+        # stepped as a copy and its holder keeps what it holds
+        state, self.state = self.state, None
+        if _held_elsewhere(state):
+            state = jax.tree.map(jnp.copy, state)
         logits, self.state = _decode_step(self.params, self.cfg, toks,
-                                          self.state, lengths)
+                                          state, lengths)
         self.decode_steps += 1
         nxt = np.asarray(jnp.argmax(logits, axis=-1))
         finished = []

@@ -217,7 +217,6 @@ def build_cell(arch: str, shape_name: str, mesh, variant=None):
       moe_impl          — 'ep' | 'ragged'
       capacity_factor   — MoE EP capacity factor
       state_seq_axis    — mesh axis to shard decode KV seq dim over
-      cache_mode        — decode cache: 'scan_xs' | 'carry' (in-place)
     """
     v = variant or {}
     overrides = v.get("weight_overrides")
@@ -277,8 +276,7 @@ def build_cell(arch: str, shape_name: str, mesh, variant=None):
     def serve_step(params, tokens, state, lengths):
         return decode_step(params, cfg, tokens, state, lengths,
                            moe_impl=moe_impl,
-                           capacity_factor=v.get("capacity_factor", 1.25),
-                           cache_mode=v.get("cache_mode", "scan_xs"))
+                           capacity_factor=v.get("capacity_factor", 1.25))
 
     fn = jax.jit(serve_step, donate_argnums=(2,))
     return fn, (p_sds, ins["tokens"], ins["state"], ins["lengths"])

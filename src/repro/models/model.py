@@ -4,7 +4,9 @@ needs:
 
 * ``forward``       — full-sequence (train / whole-prompt prefill);
                       optionally returns the KV/state caches it produced.
-* ``decode_step``   — one token per sequence against a decode state.
+* ``decode_step``   — one token per sequence against a decode state;
+                      dense/vlm update their stacked cache in place
+                      (one K and V row per sequence per layer).
 * ``append_forward``— engine path: prefill an appended chunk against an
                       existing (padded) prefix KV — the agentic
                       short-append pattern the paper optimises.
@@ -12,11 +14,15 @@ needs:
 Decode state layout (stacked along layer groups, mirroring the param
 stacking so a single scan consumes both):
 
-* dense/vlm:  {"k": (L,b,S,hkv,dh), "v": ...}
+* dense/vlm:  {"kv": {"k": (L,b,S,hkv,dh), "v": ...}}, carried whole by
+              the decode scan
 * moe:        {"dense": {...(f)}, "pre": {...(n_super,p-1)}, "moe": {...(n_super)}}
 * mla:        {"c": (L,b,S,r), "krope": (L,b,S,rd)}
 * ssm:        {stacked ssm state dicts (L,...)}
 * hybrid:     {"mamba": (n_super, period, ...), "shared": {"k","v": (n_apps,b,S,hkv,dh)}}
+
+The moe, ssm and hybrid decodes stream each layer's cache through the
+scan's xs/ys.
 """
 from __future__ import annotations
 
@@ -28,7 +34,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers, mla as mla_lib, moe as moe_lib, ssm as ssm_lib
 from repro.models.layers import rms_norm
-from repro.models.sharding import constrain
+from repro.models.sharding import constrain, current_mesh
 
 BIG_WINDOW = 1 << 30
 
@@ -87,9 +93,47 @@ def _attn_full(p, cfg: ModelConfig, x, positions, is_local):
     return layers.attn_out(p, o), {"k": k, "v": v}
 
 
-def _attn_decode(p, cfg: ModelConfig, x, cache, lengths, is_local):
+# Lanes of a TPU vector register.  One chip stores the stacked cache
+# (L, b, S, hkv, dh) with the sequence axis in lanes, so dh = 64 is not
+# padded; a write narrower than one lane tile of that axis, or a
+# scatter, makes XLA relayout the whole cache twice a step.
+_LANES = 128
+
+
+def _put_rows(buf, layer, lengths, rows):
+    """``buf`` (L, b, S, ...) with ``rows[i]`` written at
+    ``[layer, i, lengths[i]]``, in place when ``buf`` is a donated carry.
+
+    Unpartitioned, each sequence's row goes in through the lane-aligned
+    window of tokens that holds it: read, blended, written back.  Over a
+    mesh (:func:`repro.models.sharding.current_mesh`) the rows go in by
+    one scatter, which partitions along batch and sequence where a
+    per-sequence window would gather the whole cache to every device.
+    A position at or past S writes nothing either way."""
+    b, s = buf.shape[1], buf.shape[2]
+    rows = rows.astype(buf.dtype)
+    if current_mesh() is not None:
+        return buf.at[layer, jnp.arange(b), lengths].set(rows)
+    w = min(_LANES, s)
+    starts = jnp.minimum(lengths // w * w, s - w)
+    zeros = (jnp.zeros((), lengths.dtype),) * (buf.ndim - 3)
+    unit = (1,) * (buf.ndim - 3)
+    for i in range(b):
+        at = (layer, jnp.asarray(i, lengths.dtype), starts[i]) + zeros
+        win = jax.lax.dynamic_slice(buf, at, (1, 1, w) + buf.shape[3:])
+        hit = (jnp.arange(w) == lengths[i] - starts[i]).reshape(
+            (1, 1, w) + unit)
+        buf = jax.lax.dynamic_update_slice(
+            buf, jnp.where(hit, rows[i], win), at)
+    return buf
+
+
+def _attn_decode(p, cfg: ModelConfig, x, cache, lengths, is_local,
+                 layer=None):
     """x (b,1,d); cache holds padded buffers; lengths (b,) = tokens already
-    cached.  Writes the new token at index `lengths`."""
+    cached.  Writes the new token at index `lengths`.  With ``layer`` the
+    buffers are the whole stack (L,b,S,...): the token's rows go to
+    ``[layer, b, lengths]`` and attention reads layer ``layer``."""
     b = x.shape[0]
     bidx = jnp.arange(b)
     if cfg.attn_variant == "mla":
@@ -99,23 +143,31 @@ def _attn_decode(p, cfg: ModelConfig, x, cache, lengths, is_local):
         o = mla_lib.mla_decode(p, cfg, x, c_cache, kr_cache, lengths + 1)
         return o, {"c": c_cache, "krope": kr_cache}
     q, k, v = layers.gqa_qkv(p, cfg, x, lengths[:, None])
-    k_cache = cache["k"].at[bidx, lengths].set(k[:, 0])
-    v_cache = cache["v"].at[bidx, lengths].set(v[:, 0])
-    o = layers.decode_attend(q, k_cache, v_cache, lengths + 1,
+    if layer is None:
+        k_cache = cache["k"].at[bidx, lengths].set(k[:, 0])
+        v_cache = cache["v"].at[bidx, lengths].set(v[:, 0])
+    else:
+        k_cache = _put_rows(cache["k"], layer, lengths, k[:, 0])
+        v_cache = _put_rows(cache["v"], layer, lengths, v[:, 0])
+    k_read, v_read = ((k_cache, v_cache) if layer is None
+                      else (k_cache[layer], v_cache[layer]))
+    o = layers.decode_attend(q, k_read, v_read, lengths + 1,
                              window=_window_for(cfg, is_local),
                              softcap=cfg.attn_logit_softcap)
     return layers.attn_out(p, o), {"k": k_cache, "v": v_cache}
 
 
 def _dense_block(p, cfg: ModelConfig, h, *, mode, positions=None,
-                 cache=None, lengths=None, is_local=False,
+                 cache=None, lengths=None, is_local=False, layer=None,
                  moe_impl=None, is_moe=False, capacity_factor=1.25):
-    """One transformer block (attention + FFN/MoE) in full or decode mode."""
+    """One transformer block (attention + FFN/MoE) in full or decode mode;
+    ``layer`` as in :func:`_attn_decode`."""
     xn = rms_norm(h, p["ln1"], cfg.rms_norm_eps)
     if mode == "full":
         attn, kv = _attn_full(p["attn"], cfg, xn, positions, is_local)
     else:
-        attn, kv = _attn_decode(p["attn"], cfg, xn, cache, lengths, is_local)
+        attn, kv = _attn_decode(p["attn"], cfg, xn, cache, lengths,
+                                is_local, layer)
     if cfg.post_attn_norm:
         attn = rms_norm(attn, p["ln1b"], cfg.rms_norm_eps)
     h = h + attn * cfg.ffn_mult
@@ -340,56 +392,33 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_seq: int,
 
 
 def decode_step(params, cfg: ModelConfig, tokens, state, lengths, *,
-                moe_impl: str = "ragged", capacity_factor: float = 1.25,
-                cache_mode: str = "scan_xs"):
+                moe_impl: str = "ragged", capacity_factor: float = 1.25):
     """One decode step.  tokens (b,) int32; lengths (b,) = #tokens already
     cached.  Returns (logits (b, vocab), new_state).
 
-    ``cache_mode``:
-      * 'scan_xs' — caches stream through scan xs/ys (simple, but XLA
-        double-buffers the stacked cache: ~2× KV residency);
-      * 'carry'   — the stacked cache rides the scan *carry* with
-        per-layer dynamic slice/update, which XLA aliases in place
-        (§Perf iteration: ~1× KV residency).  Dense/vlm families.
+    Dense/vlm: the stacked cache rides the layer scan's carry, and each
+    layer writes only the step's K and V row per sequence into it
+    (``[layer, b, lengths]``) and reads its own layer from it.  Jitted
+    with the state donated (``engines/runtime.py``), XLA updates the
+    cache in place: no layer's slice is copied out or written back, and
+    one cache is live, not two.  The other families stream their
+    per-layer caches through the scan's xs/ys.
     """
     assert cfg.supports_decode, cfg.name
     h = embed(params, cfg, tokens[:, None])
     fam = cfg.family
 
-    if cache_mode == "carry" and fam in ("dense", "vlm"):
-        is_local = _is_local_arr(cfg)
-
+    if fam in ("dense", "vlm"):
         def body(carry, xs):
             hh, kv = carry
             blk, loc, li = xs
-            cache = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, li, 0,
-                                                       keepdims=False), kv)
-            hh, new_cache = _dense_block(blk, cfg, hh, mode="decode",
-                                         cache=cache, lengths=lengths,
-                                         is_local=loc)
-            kv = jax.tree.map(
-                lambda full, c: jax.lax.dynamic_update_index_in_dim(
-                    full, c.astype(full.dtype), li, 0), kv, new_cache)
+            hh, kv = _dense_block(blk, cfg, hh, mode="decode", cache=kv,
+                                  lengths=lengths, is_local=loc, layer=li)
             return (hh, kv), None
 
         (h, kvs), _ = jax.lax.scan(
             body, (h, state["kv"]),
-            (params["blocks"], is_local, jnp.arange(cfg.n_layers)))
-        logits = logits_from_hidden(params, cfg, h)[:, 0]
-        return logits, {"kv": kvs}
-
-    if fam in ("dense", "vlm"):
-        is_local = _is_local_arr(cfg)
-
-        def body(hh, xs):
-            blk, loc, cache = xs
-            hh, kv = _dense_block(blk, cfg, hh, mode="decode", cache=cache,
-                                  lengths=lengths, is_local=loc)
-            return hh, kv
-
-        h, kvs = jax.lax.scan(body, h,
-                              (params["blocks"], is_local, state["kv"]))
+            (params["blocks"], _is_local_arr(cfg), jnp.arange(cfg.n_layers)))
         new_state = {"kv": kvs}
 
     elif fam == "moe":

@@ -1,16 +1,21 @@
-"""Ahead-of-time compiles of the Pallas kernels for a v5e chip.
+"""Ahead-of-time compiles of the Pallas kernels and the served decode
+step for a v5e chip.
 
 Nothing here runs on a chip: each kernel is lowered and compiled by the
 TPU compiler for a *described* v5e device at qwen1.5-0.5b widths (16 kv
 heads, head_dim 64, 24 layers, bf16 KV, 16-token FullBlock pages), so a
 BlockSpec or VMEM budget the chip would refuse fails here even though
-the interpret-mode tests pass.
+the interpret-mode tests pass.  The decode step is compiled at the chip
+benchmark's size, where the compiler's own layouts decide whether the
+cache is updated in place.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process may hold the TPU library, and every
 pytest-xdist worker imports this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,10 +23,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.engines import runtime
 from repro.engines.kvio import kv_row_bytes, n_attn_layers
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kv_gather import kv_layer_gather, kv_layer_scatter
 from repro.kernels.paged_attention import paged_attention
+from repro.models import init_decode_state, init_params
 
 PAGE_TOKENS = 16          # chip_smoke.py's FullBlock size
 
@@ -85,3 +92,29 @@ def test_paged_attention_compile(one_chip, qwen):
     lengths = _sds((b,), jnp.int32, one_chip)
     _assert_kernel(paged_attention.lower(q, pool, pool, table,
                                          lengths).compile())
+
+
+def test_decode_step_updates_cache_in_place(one_chip, qwen):
+    """8 slots of 4096 tokens: the compiled step aliases the whole cache
+    to its output, copies no array a layer slice large or larger (no
+    relayout of the cache, no layer slice copied out), and needs no
+    scratch near a layer slice's size."""
+    b, s = 8, 4096
+    shape = lambda a: _sds(a.shape, a.dtype, one_chip)
+    params = jax.tree.map(shape, jax.eval_shape(
+        lambda: init_params(qwen, jax.random.PRNGKey(0))))
+    state = jax.tree.map(shape, init_decode_state(qwen, b, s,
+                                                  abstract=True))
+    ids = _sds((b,), jnp.int32, one_chip)
+    compiled = runtime._decode_step.lower(params, qwen, ids, state,
+                                          ids).compile()
+    kv_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                   for a in jax.tree.leaves(state))
+    layer_elems = b * s * qwen.n_kv_heads * qwen.head_dim
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == kv_bytes
+    assert mem.temp_size_in_bytes < 2 * layer_elems // 8
+    copied = [math.prod(int(d) for d in dims.split(","))
+              for dims in re.findall(r"= bf16\[([\d,]+)\]\S* copy\(",
+                                     compiled.as_text())]
+    assert not [n for n in copied if n >= layer_elems]
