@@ -2,8 +2,11 @@
 
 [arXiv:2404.06395; hf]
 40L d_model=2304 36H (GQA kv=36 = MHA) d_ff=5760 vocab=122753.
-MiniCPM's mup-style residual scaling is carried as ``ffn_mult``
-(depth-scaled residual multiplier 1.4/sqrt(40)); the WSD learning-rate
+MiniCPM's mup-style scalings: the embeddings are multiplied by
+``scale_emb`` = 12 (``embed_scale``) and each residual branch by
+``scale_depth``/sqrt(L) = 1.4/sqrt(40) (``ffn_mult``).  Its logit
+divisor hidden_size/dim_model_base = 9 is not carried: greedy decoding
+does not see a positive scale of the logits.  The WSD learning-rate
 schedule lives in repro.training.schedules.
 """
 from repro.configs.base import ModelConfig, register
@@ -20,6 +23,7 @@ CONFIG = register(ModelConfig(
     d_ff=5760,
     ffn_activation="silu_gated",
     tie_embeddings=True,
+    embed_scale=12.0,
     ffn_mult=1.4 / (40 ** 0.5),
     rope_theta=10_000.0,
     sharding_profile="tp",

@@ -20,6 +20,7 @@ LayerBlock granularity.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -57,6 +58,21 @@ def slot_set(state, axes, slot: int, sub):
     return jax.tree.map(
         lambda a, ax, s: jax.lax.dynamic_update_slice_in_dim(a, s, slot, ax),
         state, axes, sub)
+
+
+@functools.partial(jax.jit, static_argnums=1, donate_argnums=0)
+def _put_slot(state, axes: tuple, slot, sub):
+    leaves, tree = jax.tree.flatten(state)
+    return jax.tree.unflatten(tree, [
+        jax.lax.dynamic_update_slice_in_dim(a, s.astype(a.dtype), slot, ax)
+        for a, ax, s in zip(leaves, axes, jax.tree.leaves(sub))])
+
+
+def put_slot(state, axes, slot: int, sub):
+    """:func:`slot_set` that takes ``state`` over: one program per state
+    shape (``slot`` is traced) writes ``sub`` into it in place, and the
+    buffers passed in are deleted."""
+    return _put_slot(state, tuple(jax.tree.leaves(axes)), slot, sub)
 
 
 # ---------------------------------------------------------------------------
@@ -129,45 +145,72 @@ def serialize_kv(cfg: ModelConfig, state, slot: int, t0: int,
                      for li in range(len(_kv_rows(cfg)))], axis=0)
 
 
-def deserialize_kv_layer(cfg: ModelConfig, state, slot: int, t0: int,
-                         layer: int, row: np.ndarray):
-    """Write one layer's (T, row_bytes) uint8 rows into the state —
-    the per-LayerBlock HBM placement step of layerwise loading."""
+@functools.partial(jax.jit, donate_argnums=0)
+def _place_rows(group, at, rows):
+    """``group``'s leaves, each (*stack, b, S, ...), with ``rows[key]``
+    (T, ...) written at ``at`` = (*stack, slot, t0) and on: traced
+    indices, so one program per row count; the group is donated."""
+    out = {}
+    for key, a in group.items():
+        r = rows[key].astype(a.dtype)
+        r = r.reshape((1,) * (len(at) - 1) + r.shape)
+        out[key] = jax.lax.dynamic_update_slice(
+            a, r, tuple(at) + (0,) * (a.ndim - len(at)))
+    return out
+
+
+def put_kv_layer(cfg: ModelConfig, state, slot: int, t0: int, layer: int,
+                 row: np.ndarray):
+    """Write one layer's (T, row_bytes) uint8 rows into the state — the
+    per-LayerBlock HBM placement step of layerwise loading — taking the
+    state over: the buffers of the layer group written (``state[key]``
+    of ``_kv_rows``) are updated in place and deleted.  ``slot``, ``t0``
+    and the layer's stack index are traced, so every layer of a group
+    runs one program per row count."""
     key, idx = _kv_rows(cfg)[layer]
     T = row.shape[0]
     dt = jnp.dtype(cfg.kv_cache_dtype)
     if cfg.attn_variant == "mla":
         r = cfg.mla.kv_lora_rank
-        rd = cfg.mla.rope_head_dim
         c = row[:, :r * dt.itemsize].copy().view(dt).reshape(T, r)
-        kr = row[:, r * dt.itemsize:].copy().view(dt).reshape(T, rd)
-        upd = {"c": jnp.asarray(c), "krope": jnp.asarray(kr)}
+        kr = row[:, r * dt.itemsize:].copy().view(dt).reshape(
+            T, cfg.mla.rope_head_dim)
+        upd = {"c": c, "krope": kr}
     else:
         half = cfg.n_kv_heads * cfg.head_dim * dt.itemsize
         k = row[:, :half].copy().view(dt).reshape(
             T, cfg.n_kv_heads, cfg.head_dim)
         v = row[:, half:].copy().view(dt).reshape(
             T, cfg.n_kv_heads, cfg.head_dim)
-        upd = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+        upd = {"k": k, "v": v}
     new_state = dict(state)
-    comp = dict(new_state[key])
-    for ckey, val in upd.items():
-        arr = comp[ckey]
-        comp[ckey] = arr.at[
-            idx + (slot, slice(t0, t0 + val.shape[0]))].set(
-            val.astype(arr.dtype))
-    new_state[key] = comp
+    new_state[key] = _place_rows(state[key], idx + (slot, t0), upd)
     return new_state
+
+
+def _copy_group(state, key):
+    return {**state, key: jax.tree.map(jnp.copy, state[key])}
+
+
+def deserialize_kv_layer(cfg: ModelConfig, state, slot: int, t0: int,
+                         layer: int, row: np.ndarray):
+    """:func:`put_kv_layer` on a copy of the layer group it writes: the
+    state passed in stays valid.  The write is the same program."""
+    state = _copy_group(state, _kv_rows(cfg)[layer][0])
+    return put_kv_layer(cfg, state, slot, t0, layer, row)
 
 
 def deserialize_kv(cfg: ModelConfig, state, slot: int, t0: int,
                    kv_bytes: np.ndarray):
-    """Write (L, T, row_bytes) uint8 back into the padded state buffers."""
+    """Write (L, T, row_bytes) uint8 back into the padded state buffers
+    (a copy: the state passed in stays valid)."""
     rows = _kv_rows(cfg)
     L = kv_bytes.shape[0]
     assert L == len(rows), (L, len(rows))
+    for key in {key for key, _ in rows}:
+        state = _copy_group(state, key)
     for li in range(L):
-        state = deserialize_kv_layer(cfg, state, slot, t0, li, kv_bytes[li])
+        state = put_kv_layer(cfg, state, slot, t0, li, kv_bytes[li])
     return state
 
 

@@ -18,6 +18,7 @@ SSM/hybrid archs carry an opaque state-blob instead of per-token KV
 """
 from __future__ import annotations
 
+import functools
 import pickle
 import sys
 from dataclasses import dataclass, field
@@ -43,10 +44,23 @@ PAGED_FAMILIES = ("dense", "vlm", "moe")
 
 # Compiled once per shape with the config static.  Called eagerly, each
 # step re-traces its layer scan into a new program and compiles it again.
-# The decode step donates its state (argument 3): the dense cache is
-# updated in place, and the state passed in is deleted.
-_append_step = jax.jit(append_step, static_argnums=1)
+# Both steps donate their state (argument 3): the cache is updated in
+# place, and the state passed in is deleted.  Prefill computes the
+# logits of the chunk's last position only, the one the engine samples.
+_append_step = jax.jit(functools.partial(append_step, last_only=True),
+                       static_argnums=1, donate_argnums=3)
 _decode_step = jax.jit(decode_step, static_argnums=1, donate_argnums=3)
+
+
+def _take(owner, attr: str):
+    """``owner.<attr>``, detached from ``owner``, to be donated to a
+    step: the state itself, or a copy where something else still holds
+    it (:func:`_held_elsewhere`), so that holder keeps what it holds."""
+    state = getattr(owner, attr)
+    setattr(owner, attr, None)
+    if _held_elsewhere(state):
+        state = jax.tree.map(jnp.copy, state)
+    return state
 
 
 def _held_elsewhere(state) -> bool:
@@ -65,6 +79,11 @@ def _held_elsewhere(state) -> bool:
         if isinstance(node, dict):
             todo.extend(node.values())
     return False
+
+
+def state_bytes(state) -> int:
+    """Device bytes of a (per-request or decode) state."""
+    return sum(a.nbytes for a in jax.tree.leaves(state))
 
 
 def uses_state_blob(cfg: ModelConfig) -> bool:
@@ -144,6 +163,11 @@ class PrefillEngine:
             self._install(er, payload)
 
     def _install(self, er: EngineRequest, payload):
+        if er.prompt_len > self.max_seq:
+            # a chunk written past the state's end would be shifted back
+            # over earlier rows (model._put_chunk), so it is refused here
+            raise ValueError(f"prompt of {er.prompt_len} tokens is longer "
+                             f"than the state's {self.max_seq}")
         tr = self.tracer
         er.state = init_decode_state(self.cfg, 1, self.max_seq)
         hit = er.req.cached_tokens
@@ -158,12 +182,12 @@ class PrefillEngine:
                                                   track=self.track):
                     rows = rows[:hit]
                     if tr is None:
-                        er.state = kvio.deserialize_kv_layer(
+                        er.state = kvio.put_kv_layer(
                             self.cfg, er.state, 0, 0, li, rows)
                     else:
                         with tr.region(self.track, "pe.install.place",
                                        layer=li, bytes=rows.nbytes):
-                            er.state = kvio.deserialize_kv_layer(
+                            er.state = kvio.put_kv_layer(
                                 self.cfg, er.state, 0, 0, li, rows)
             else:
                 kv_bytes = np.concatenate(payload, axis=1)   # (L, hit, row)
@@ -219,7 +243,7 @@ class PrefillEngine:
             t = jnp.asarray([toks], jnp.int32)
             lengths = jnp.asarray([er.length], jnp.int32)
             logits, er.state = _append_step(self.params, self.cfg, t,
-                                            er.state, lengths)
+                                            _take(er, "state"), lengths)
             er.length += bi.bsz
             self.prefill_tokens += bi.bsz
             if er.length == er.prompt_len:
@@ -271,14 +295,26 @@ class DecodeEngine:
         return sum(s is None for s in self.slots)
 
     def admit(self, er: EngineRequest) -> int:
+        """Take ``er`` into a free slot, its prefill state written into
+        the decode state in place (donated, as a decode step does).
+        With a tracer: the host region ``de.admit``."""
         slot = self.slots.index(None)
         self.slots[slot] = er
-        self.state = kvio.slot_set(self.state, self.axes, slot, er.state)
+        if self.tracer is None:
+            self._admit_state(slot, er)
+        else:
+            with self.tracer.region(self.track, "de.admit", slot=slot,
+                                    bytes=state_bytes(er.state)):
+                self._admit_state(slot, er)
         self.lengths[slot] = er.length
         self.next_token[slot] = er.first_token
         er.generated.append(er.first_token)
         er.state = None                      # DE owns the state now
         return slot
+
+    def _admit_state(self, slot: int, er: EngineRequest) -> None:
+        self.state = kvio.put_slot(_take(self, "state"), self.axes, slot,
+                                   er.state)
 
     def step(self) -> List[EngineRequest]:
         """One decode step over all active slots; returns finished.
@@ -301,11 +337,8 @@ class DecodeEngine:
         # the step takes the state over (donated): its buffers are then
         # deleted, so a state that is also held outside the engine is
         # stepped as a copy and its holder keeps what it holds
-        state, self.state = self.state, None
-        if _held_elsewhere(state):
-            state = jax.tree.map(jnp.copy, state)
         logits, self.state = _decode_step(self.params, self.cfg, toks,
-                                          state, lengths)
+                                          _take(self, "state"), lengths)
         self.decode_steps += 1
         nxt = np.asarray(jnp.argmax(logits, axis=-1))
         finished = []

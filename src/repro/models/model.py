@@ -15,14 +15,14 @@ Decode state layout (stacked along layer groups, mirroring the param
 stacking so a single scan consumes both):
 
 * dense/vlm:  {"kv": {"k": (L,b,S,hkv,dh), "v": ...}}, carried whole by
-              the decode scan
+              the decode and append scans
 * moe:        {"dense": {...(f)}, "pre": {...(n_super,p-1)}, "moe": {...(n_super)}}
 * mla:        {"c": (L,b,S,r), "krope": (L,b,S,rd)}
 * ssm:        {stacked ssm state dicts (L,...)}
 * hybrid:     {"mamba": (n_super, period, ...), "shared": {"k","v": (n_apps,b,S,hkv,dh)}}
 
-The moe, ssm and hybrid decodes stream each layer's cache through the
-scan's xs/ys.
+The moe, ssm and hybrid decodes and appends stream each layer's cache
+through the scan's xs/ys.
 """
 from __future__ import annotations
 
@@ -504,8 +504,28 @@ def decode_step(params, cfg: ModelConfig, tokens, state, lengths, *,
 # ---------------------------------------------------------------------------
 
 
-def _attn_append(p, cfg: ModelConfig, x, cache, lengths, is_local):
-    """x (b,s,d); writes the chunk's K/V at [lengths, lengths+s)."""
+def _put_chunk(buf, layer, lengths, rows):
+    """``buf`` (L, b, S, ...) with ``rows[i]`` (s, ...) written at
+    ``[layer, i, lengths[i]:lengths[i] + s]``, in place when ``buf`` is a
+    donated carry.  Unlike one row (:func:`_put_rows`), a chunk's
+    dynamic-update-slice keeps the stored layout (sequence in lanes): no
+    relayout of the cache (compiled for a v5e, minicpm-2b and
+    qwen1.5-0.5b).  The chunk has to end at or before S: a later start
+    is clamped, which would shift the chunk back over earlier rows, so
+    ``PrefillEngine`` refuses a prompt longer than its state."""
+    rows = rows.astype(buf.dtype)
+    zeros = (jnp.zeros((), lengths.dtype),) * (buf.ndim - 3)
+    for i in range(buf.shape[1]):
+        at = (layer, jnp.asarray(i, lengths.dtype), lengths[i]) + zeros
+        buf = jax.lax.dynamic_update_slice(buf, rows[i][None, None], at)
+    return buf
+
+
+def _attn_append(p, cfg: ModelConfig, x, cache, lengths, is_local,
+                 layer=None):
+    """x (b,s,d); writes the chunk's K/V at [lengths, lengths+s).  With
+    ``layer`` the buffers are the whole stack (L,b,S,...), as in
+    :func:`_attn_decode`."""
     b, s, _ = x.shape
     bidx = jnp.arange(b)[:, None]
     positions = lengths[:, None] + jnp.arange(s)[None, :]
@@ -514,18 +534,27 @@ def _attn_append(p, cfg: ModelConfig, x, cache, lengths, is_local):
                                         cache["krope"], lengths)
         return o, {"c": c, "krope": kr}
     q, k, v = layers.gqa_qkv(p, cfg, x, positions)
-    k_cache = cache["k"].at[bidx, positions].set(k.astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, positions].set(v.astype(cache["v"].dtype))
-    o = layers.append_attend(q, k_cache, v_cache, lengths,
+    if layer is None:
+        k_cache = cache["k"].at[bidx, positions].set(
+            k.astype(cache["k"].dtype))
+        v_cache = cache["v"].at[bidx, positions].set(
+            v.astype(cache["v"].dtype))
+        k_read, v_read = k_cache, v_cache
+    else:
+        k_cache = _put_chunk(cache["k"], layer, lengths, k)
+        v_cache = _put_chunk(cache["v"], layer, lengths, v)
+        k_read, v_read = k_cache[layer], v_cache[layer]
+    o = layers.append_attend(q, k_read, v_read, lengths,
                              window=_window_for(cfg, is_local),
                              softcap=cfg.attn_logit_softcap)
     return layers.attn_out(p, o), {"k": k_cache, "v": v_cache}
 
 
 def _append_block(p, cfg, h, cache, lengths, is_local=False, is_moe=False,
-                  moe_impl="ragged", capacity_factor=1.25):
+                  moe_impl="ragged", capacity_factor=1.25, layer=None):
     xn = rms_norm(h, p["ln1"], cfg.rms_norm_eps)
-    attn, kv = _attn_append(p["attn"], cfg, xn, cache, lengths, is_local)
+    attn, kv = _attn_append(p["attn"], cfg, xn, cache, lengths, is_local,
+                            layer)
     if cfg.post_attn_norm:
         attn = rms_norm(attn, p["ln1b"], cfg.rms_norm_eps)
     h = h + attn * cfg.ffn_mult
@@ -549,30 +578,34 @@ def _mamba_append(p, cfg, h, state):
 
 
 def append_step(params, cfg: ModelConfig, tokens, state, lengths, *,
-                moe_impl: str = "ragged", capacity_factor: float = 1.25):
+                moe_impl: str = "ragged", capacity_factor: float = 1.25,
+                last_only: bool = False):
     """Prefill an append chunk against existing decode state.
 
     tokens (b, s_app) int32 (or (b, s_app, frontend_dim) embeddings);
     lengths (b,) = tokens already cached.  Returns
-    (logits (b, s_app, vocab), new_state).  This is the engine's
-    layerwise-prefill compute step: the cache for layer l is consumed and
-    produced per scan iteration, which is exactly the LayerBlock stream
-    the dual-path loader moves.
+    (logits (b, s_app, vocab), new_state); with ``last_only`` the logits
+    of the chunk's last position only, (b, 1, vocab), which is all the
+    engine samples.  This is the engine's layerwise-prefill compute
+    step.  Dense/vlm carry the stacked cache through the layer scan, as
+    :func:`decode_step` does: each layer writes its chunk's rows in place
+    (jitted with the state donated) and reads its own layer.  The other
+    families consume and produce each layer's cache per scan iteration.
     """
     h = embed(params, cfg, tokens)
     fam = cfg.family
 
     if fam in ("dense", "vlm"):
-        is_local = _is_local_arr(cfg)
+        def body(carry, xs):
+            hh, kv = carry
+            blk, loc, li = xs
+            hh, kv = _append_block(blk, cfg, hh, kv, lengths, is_local=loc,
+                                   layer=li)
+            return (hh, kv), None
 
-        def body(hh, xs):
-            blk, loc, cache = xs
-            hh, kv = _append_block(blk, cfg, hh, cache, lengths, is_local=loc)
-            return hh, kv
-
-        h, kvs = jax.lax.scan(body, h,
-                              (params["blocks"], _is_local_arr(cfg),
-                               state["kv"]))
+        (h, kvs), _ = jax.lax.scan(
+            body, (h, state["kv"]),
+            (params["blocks"], _is_local_arr(cfg), jnp.arange(cfg.n_layers)))
         new_state = {"kv": kvs}
 
     elif fam == "moe":
@@ -646,6 +679,8 @@ def append_step(params, cfg: ModelConfig, tokens, state, lengths, *,
     else:  # pragma: no cover
         raise ValueError(fam)
 
+    if last_only:
+        h = h[:, -1:]
     return logits_from_hidden(params, cfg, h), new_state
 
 

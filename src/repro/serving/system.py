@@ -63,7 +63,8 @@ from repro.core.scheduler import Request, Scheduler
 from repro.core.traffic import TrafficClass, TrafficManager
 from repro.engines import kvio
 from repro.engines.runtime import (DecodeEngine, EngineRequest,
-                                   PrefillEngine, uses_state_blob)
+                                   PrefillEngine, state_bytes,
+                                   uses_state_blob)
 from repro.obs.schema import conforming
 from repro.kvcache.store import MemoryKVStore, StateBlobStore
 from repro.kvcache.tiers import DramTier, ThinkTimePrefetcher
@@ -271,6 +272,7 @@ class ServingSystem:
         # _stamp uses), so span edges match the stamped milestones.
         self.tracer = tracer
         self._pending_states: List[Tuple[EngineRequest, ReqState]] = []
+        self._prefill_state_bytes = 0
         if tracer is not None:
             tracer.bind_clock(lambda: self.clock.now)
             if self.faults is not None:
@@ -1353,6 +1355,13 @@ class ServingSystem:
             act = self._tick_phases()
             r.args.update(inflight=len(self._inflight), decode_steps=sum(
                 de.decode_steps for de in self.des.values()) - steps0)
+        # device bytes of the per-request prefill states alive (installed,
+        # prefilling, or waiting for a decode slot), when it changes
+        held = sum(state_bytes(er.state) for er in self._inflight.values()
+                   if er.state is not None)
+        if held != self._prefill_state_bytes:
+            self._prefill_state_bytes = held
+            tr.counter("serve", prefill_state_bytes=held)
         return act
 
     def _tick_phases(self) -> int:

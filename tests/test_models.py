@@ -152,6 +152,32 @@ def test_param_counts_match_designations():
         assert abs(got - n) / n < tol, (name, got / 1e9)
 
 
+def test_minicpm_registry_matches_benchmark_config():
+    """The registry's minicpm-2b is the model the chip benchmark's
+    configuration file states, on every key the benchmark carries over
+    (``bench.program_config``), so the CPU tests and ``launch/serve.py``
+    run it with its muP scalings."""
+    import json
+    import math
+    from pathlib import Path
+    conf = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "chip" / "configs" / "minicpm-2b.json").read_text())
+    cfg = get_config("minicpm-2b")
+    layers = conf["num_hidden_layers"]
+    heads = conf["num_attention_heads"]
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads,
+            cfg.head_dim, cfg.d_ff, cfg.vocab_size) == (
+        conf["hidden_size"], layers, heads, conf["num_key_value_heads"],
+        conf["hidden_size"] // heads, conf["intermediate_size"],
+        conf["vocab_size"])
+    assert cfg.rms_norm_eps == conf["rms_norm_eps"]
+    assert cfg.rope_theta == conf["rope_theta"]
+    assert cfg.tie_embeddings == conf["tie_word_embeddings"]
+    assert cfg.qkv_bias == conf["qkv_bias"]
+    assert cfg.embed_scale == conf["scale_emb"]
+    assert math.isclose(cfg.ffn_mult, conf["scale_depth"] / math.sqrt(layers))
+
+
 def test_active_params():
     a = count_active_params_analytic(get_config("llama4-maverick-400b-a17b"))
     assert 10e9 < a < 20e9          # a17b

@@ -387,6 +387,7 @@ REGION_ARGS = {
     "pe.install.gather": {"layer", "bytes"},
     "pe.install.place": {"layer", "bytes"},
     "de.decode": {"slots"},
+    "de.admit": {"slot", "bytes"},
     "de.persist": {"rid", "blocks", "bytes"},
     "de.persist.copy": {"bytes"},
     "store.write": {"bytes"},
@@ -501,6 +502,27 @@ def test_served_path_records_every_region_with_its_args(region_run):
     assert steps == sum(de.decode_steps
                         for de in region_run["system"].des.values())
     assert steps == sum(1 for r in tr.regions if r.name == "de.decode")
+
+
+def test_admit_region_and_prefill_state_counter(region_run):
+    """Each admission is a ``de.admit`` region inside a tick, with the
+    slot and the bytes of the request's state it writes; the ``serve``
+    counter ``prefill_state_bytes`` follows the per-request states alive
+    in whole states, and is 0 once every round is admitted."""
+    from repro.engines.runtime import state_bytes
+    from repro.models import init_decode_state
+    s, tr = region_run["system"], region_run["tracer"]
+    one = state_bytes(init_decode_state(s.cfg, 1, 176))
+    admits = [r for r in tr.regions if r.name == "de.admit"]
+    assert len(admits) == s.stats()["finished_rounds"] == 6
+    assert {r.args["slot"] for r in admits} <= {0, 1}
+    assert all(r.args["bytes"] == one and r.parent.name == "serve.tick"
+               for r in admits)
+    held = [v["prefill_state_bytes"] for _, track, _, v in tr.counters
+            if track == "serve"]
+    assert held and held[-1] == 0 and max(held) >= one
+    assert all(v % one == 0 and v <= 3 * one for v in held)
+    assert all(a != b for a, b in zip(held, held[1:]))
 
 
 def test_every_region_is_on_the_device_trace_host_plane(region_run):

@@ -1,5 +1,5 @@
-"""Ahead-of-time compiles of the Pallas kernels and the served decode
-step for a v5e chip.
+"""Ahead-of-time compiles of the Pallas kernels and the served steps for
+a v5e chip.
 
 Nothing here runs on a chip: each kernel is lowered and compiled by the
 TPU compiler for a *described* v5e device at qwen1.5-0.5b widths (16 kv
@@ -7,7 +7,10 @@ heads, head_dim 64, 24 layers, bf16 KV, 16-token FullBlock pages), so a
 BlockSpec or VMEM budget the chip would refuse fails here even though
 the interpret-mode tests pass.  The decode step is compiled at the chip
 benchmark's size, where the compiler's own layouts decide whether the
-cache is updated in place.
+cache is updated in place; admit's slot write, the install's per-layer
+placement and the prefill step at minicpm-2b's published widths and its
+cell's serving size (4 slots of 2048 tokens), where one copy of a state
+more than the chip needs would not fit beside 5.45 GB of weights.
 
 The topology is described inside a module fixture, never while a module
 is imported: only one process may hold the TPU library, and every
@@ -23,7 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.engines import runtime
+from repro.engines import kvio, runtime
 from repro.engines.kvio import kv_row_bytes, n_attn_layers
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kv_gather import kv_layer_gather, kv_layer_scatter
@@ -48,6 +51,14 @@ def one_chip():
 @pytest.fixture(scope="module")
 def qwen():
     return get_config("qwen1.5-0.5b")
+
+
+@pytest.fixture(scope="module")
+def minicpm():
+    return get_config("minicpm-2b")
+
+
+MINICPM_SLOTS, MINICPM_SEQ = 4, 2048     # configs/minicpm-2b.json serve
 
 
 def _sds(shape, dtype, sharding):
@@ -118,3 +129,76 @@ def test_decode_step_updates_cache_in_place(one_chip, qwen):
               for dims in re.findall(r"= bf16\[([\d,]+)\]\S* copy\(",
                                      compiled.as_text())]
     assert not [n for n in copied if n >= layer_elems]
+
+
+def _state(cfg, b, sharding):
+    return jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding),
+                        init_decode_state(cfg, b, MINICPM_SEQ,
+                                          abstract=True))
+
+
+def _nbytes(tree):
+    return sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(tree))
+
+
+def _slot_layer(cfg):
+    """Elements of one layer of one slot's K (or V)."""
+    return MINICPM_SEQ * cfg.n_kv_heads * cfg.head_dim
+
+
+def _largest_copy(compiled):
+    return max([math.prod(int(d) for d in dims.split(","))
+                for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(",
+                                       compiled.as_text())], default=0)
+
+
+def test_admit_writes_decode_state_in_place(one_chip, minicpm):
+    """The slot write aliases the whole decode state (3.02 GB) to its
+    output and needs no second state, nor a copy of the one written."""
+    state = _state(minicpm, MINICPM_SLOTS, one_chip)
+    sub = _state(minicpm, 1, one_chip)
+    axes = tuple(jax.tree.leaves(kvio.batch_axes_of_state(minicpm)))
+    compiled = kvio._put_slot.lower(state, axes, _sds((), jnp.int32,
+                                                      one_chip),
+                                    sub).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(state) == 3019898880
+    assert mem.temp_size_in_bytes < _nbytes(sub) // 100
+    assert _largest_copy(compiled) < _slot_layer(minicpm)
+
+
+def test_install_places_layer_in_place(one_chip, minicpm):
+    """One layer's 1024 hit rows go into the one-slot state (0.755 GB)
+    in place: the state aliases its output, with no copy of it."""
+    sub = _state(minicpm, 1, one_chip)["kv"]
+    rows = _sds((1024, minicpm.n_kv_heads, minicpm.head_dim),
+                jnp.bfloat16, one_chip)
+    at = (_sds((), jnp.int32, one_chip),) * 3
+    compiled = kvio._place_rows.lower(sub, at, {"k": rows,
+                                              "v": rows}).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(sub) == 754974720
+    assert mem.temp_size_in_bytes < _nbytes(sub) // 100
+    assert _largest_copy(compiled) < _slot_layer(minicpm)
+
+
+@pytest.mark.parametrize("tokens", [64, 2048])
+def test_prefill_step_in_place_one_position(one_chip, minicpm, tokens):
+    """The prefill step aliases the request's state to its output, copies
+    no layer of it, and returns one position's logits, not one per
+    token (1.0 GB in float32 at 2048)."""
+    shape = lambda a: _sds(a.shape, a.dtype, one_chip)
+    params = jax.tree.map(shape, jax.eval_shape(
+        lambda: init_params(minicpm, jax.random.PRNGKey(0))))
+    sub = _state(minicpm, 1, one_chip)
+    compiled = runtime._append_step.lower(
+        params, minicpm, _sds((1, tokens), jnp.int32, one_chip), sub,
+        _sds((1,), jnp.int32, one_chip)).compile()
+    logits = compiled.out_info[0]
+    assert logits.shape == (1, 1, minicpm.vocab_size)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(sub)
+    assert mem.output_size_in_bytes < _nbytes(sub) + 4 * (
+        minicpm.vocab_size + 1024)
+    assert _largest_copy(compiled) < _slot_layer(minicpm)
